@@ -3,7 +3,11 @@
 Fig 8 varies cores 2→40; the container has one core, so the scale-up axis
 becomes the *device count of the sharded PBME step* (subprocess per point,
 since the device count is locked at jax init).  CPU efficiency (Table 4)
-= 1 / (runtime × devices)."""
+= 1 / (runtime × devices).
+
+The children run on virtual CPU devices.  On an accelerator host the parent
+may already hold the chip, and a child cannot build its mesh from one chip,
+so the section refuses to start there."""
 
 from __future__ import annotations
 
@@ -12,12 +16,14 @@ import os
 import subprocess
 import sys
 
+import jax
+
 from benchmarks.common import emit
 
 _CHILD = r"""
 import json, time
 import jax, numpy as np
-from repro.distributed.compat import make_mesh
+from repro.distributed import make_mesh
 from repro.core.distributed import tc_fixpoint_sharded
 from repro.data.graphs import gnp_graph
 
@@ -32,6 +38,12 @@ print(json.dumps({{"seconds": time.time() - t0, "iters": iters}}))
 
 
 def run(points=((1, 1, 1), (2, 2, 1), (4, 2, 2), (8, 4, 2))):
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"fig8 runs on virtual CPU devices in child processes; on a "
+            f"{backend!r} host the children would contend for the chip"
+        )
     base = None
     for ndev, rows, cols in points:
         env = dict(os.environ)

@@ -23,6 +23,10 @@ picking from insert / delete / query / concurrent / warm-start / txn / obs.
 ``--bench-json PATH`` appends one perf-trajectory record (git rev,
 ``--timestamp``, section -> headline seconds) to PATH after the run and
 prints the delta vs. the previous record — see ``benchmarks/trajectory.py``.
+
+A section that raises prints a ``<section>_FAILED`` row and the run goes on
+to the next section; the process then exits with status 1.  Compiled
+programs persist across runs (``repro.compile_cache``).
 """
 
 from __future__ import annotations
@@ -84,7 +88,10 @@ def main() -> None:
         "roofline",
     ]
     from benchmarks import common
+    from repro import compile_cache
 
+    compile_cache.enable()
+    failed: list[str] = []
     section_rows: dict[str, dict[str, float]] = {}
     print("name,us_per_call,derived")
     for sec in sections:
@@ -122,6 +129,7 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc(file=sys.stderr)
             print(f"{sec}_FAILED,0,{type(e).__name__}")
+            failed.append(sec)
         rows = common.ROWS[mark:]
         if rows:
             # last value wins on duplicate names within a section
@@ -137,6 +145,8 @@ def main() -> None:
         if len(records) >= 2:
             print(trajectory.format_compare(records[-2], records[-1]),
                   file=sys.stderr)
+    if failed:
+        sys.exit(f"failed sections: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

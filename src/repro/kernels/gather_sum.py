@@ -26,10 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gather_sum_kernel(idx_ref, x_row_ref, out_ref):
@@ -47,7 +44,7 @@ def _gather_sum_kernel(idx_ref, x_row_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_sum_call(
-    idx: jax.Array, x: jax.Array, *, interpret: bool = True
+    idx: jax.Array, x: jax.Array, *, interpret: bool
 ) -> jax.Array:
     """idx: int32[B, K] (-1 pad); x: f32[N, D] → f32[B, D] row sums."""
     bsz, k = idx.shape

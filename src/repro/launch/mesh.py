@@ -7,7 +7,7 @@ dry-run sees 512 placeholders).
 
 from __future__ import annotations
 
-from repro.distributed.compat import make_mesh
+from repro.distributed import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -10,7 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 from repro.models.common import dense_init
 from repro.models.gnn.common import GNNConfig, GraphBatch, edge_mask

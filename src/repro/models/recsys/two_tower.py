@@ -14,7 +14,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 from repro.models.common import mlp_apply, mlp_init
 from repro.relational.embedding import embedding_bag, sampled_softmax_loss

@@ -5,7 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 from repro.models.common import dense_init, rmsnorm, rmsnorm_init, rope
 from repro.models.transformer.config import TransformerConfig

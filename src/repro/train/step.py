@@ -20,7 +20,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.optim.adamw import adamw_update

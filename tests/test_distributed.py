@@ -11,7 +11,7 @@ import pytest
 _SCRIPT = r"""
 import json
 import jax, jax.numpy as jnp, numpy as np
-from repro.distributed.compat import make_mesh
+from repro.distributed import make_mesh
 
 out = {}
 mesh = make_mesh((4, 2), ("data", "model"))
@@ -154,7 +154,7 @@ def test_collective_bytes_parser():
 def test_param_sharding_rules():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.compat import make_mesh
+    from repro.distributed import make_mesh
     from repro.distributed.sharding import param_sharding
 
     mesh = make_mesh((1, 1), ("data", "model"))

@@ -1,7 +1,8 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles.
 
-All kernels run with ``interpret=True`` on CPU (the kernel body executes in
-Python) — the correctness contract for the TPU target.
+On the CPU every kernel runs in the Pallas interpreter
+(``ops.interpret_mode``) — the correctness contract for the TPU target;
+``test_tpu_compile.py`` checks that the TPU compiler accepts them.
 """
 
 import jax
@@ -59,6 +60,34 @@ def test_bitmm_fused_delta_sweep(shape):
     got_m = np.asarray(ref.unpack_bits(m_new))[:, :n] > 0
     assert (got_delta == exp_delta).all()
     assert (got_m == exp_m).all()
+
+
+@pytest.mark.parametrize("shape", [(128, 8192, 8192), (130, 9000, 4200)])
+def test_bitmm_word_tiled(shape):
+    """Past ``FULL_MAX`` words the kernel tiles K and N in 128-word blocks;
+    the accumulator must carry across K blocks and planes."""
+    m, k, n = shape
+    rng = np.random.default_rng(k)
+    a = rng.random((m, k)) < 0.0005
+    b = rng.random((k, n)) < 0.0005
+    cur = rng.random((m, n)) < 0.0005
+    new = (a.astype(np.float32) @ b.astype(np.float32)) > 0
+    got = np.asarray(ref.unpack_bits(ops.bitmm(_pack(a), _pack(b))))[:, :n] > 0
+    assert new.any() and (got == new).all()
+    delta, m_new = ops.bitmm_fused_delta(_pack(a), _pack(b), _pack(cur))
+    got_delta = np.asarray(ref.unpack_bits(delta))[:, :n] > 0
+    got_m = np.asarray(ref.unpack_bits(m_new))[:, :n] > 0
+    assert (got_delta == (new & ~cur)).all()
+    assert (got_m == (cur | new)).all()
+
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    assert ops.interpret_mode() is True             # CPU: the interpreter
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_mode() is False            # TPU: Mosaic compiles
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_mode()
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
