@@ -46,22 +46,30 @@ def empty_delta(arity: int, minimum: int = 128) -> jax.Array:
     return jnp.full((next_bucket(0, minimum), arity), SENTINEL, jnp.int32)
 
 
+#: The device scope of the tuple table's sort, dedup and merge programs
+#: (``jax.named_scope``: op metadata only, so a profiler trace can attribute
+#: their device time; program and op names are unchanged).
+MERGE_SCOPE = "tuple.merge"
+
+
 @functools.partial(jax.jit, static_argnames=("capacity", "domain"))
 def _sort_pad(rows: jax.Array, capacity: int, domain: int) -> jax.Array:
-    pad = jnp.full((capacity - rows.shape[0], rows.shape[1]), SENTINEL, jnp.int32)
-    rows = jnp.concatenate([rows.astype(jnp.int32), pad], axis=0)
-    key = compact_key(rows, domain)
-    order = jnp.argsort(key) if key is not None else lexsort_rows(rows)
-    return rows[order]
+    with jax.named_scope(MERGE_SCOPE):
+        pad = jnp.full((capacity - rows.shape[0], rows.shape[1]), SENTINEL, jnp.int32)
+        rows = jnp.concatenate([rows.astype(jnp.int32), pad], axis=0)
+        key = compact_key(rows, domain)
+        order = jnp.argsort(key) if key is not None else lexsort_rows(rows)
+        return rows[order]
 
 
 @functools.partial(jax.jit, static_argnames=("domain",))
 def _dedup_sorted(rows: jax.Array, domain: int) -> tuple[jax.Array, jax.Array]:
     """Sorted rows → (unique rows first + SENTINEL pads, unique count)."""
-    mask = unique_mask(rows)
-    kept = jnp.where(mask[:, None], rows, SENTINEL)
-    order = jnp.argsort(~mask, stable=True)
-    return kept[order], mask.sum()
+    with jax.named_scope(MERGE_SCOPE):
+        mask = unique_mask(rows)
+        kept = jnp.where(mask[:, None], rows, SENTINEL)
+        order = jnp.argsort(~mask, stable=True)
+        return kept[order], mask.sum()
 
 
 @functools.partial(jax.jit, static_argnames=("domain",))
@@ -247,24 +255,25 @@ def _merge_sorted(a: jax.Array, b: jax.Array, capacity: int, domain: int) -> jax
     This is the serving hot path: one merge per IDB per iteration, over
     tables that dwarf the delta.
     """
-    ka = compact_key(a, domain)
-    kb = compact_key(b, domain)
-    if ka is None or kb is None:
-        rows = jnp.concatenate([a, b], axis=0)
-        if rows.shape[0] < capacity:
-            pad = jnp.full(
-                (capacity - rows.shape[0], rows.shape[1]), SENTINEL, jnp.int32
-            )
-            rows = jnp.concatenate([rows, pad], axis=0)
-        order = lexsort_rows(rows)
-        return rows[order][:capacity]
-    pos_a = jnp.arange(a.shape[0]) + jnp.searchsorted(kb, ka, side="left")
-    pos_b = jnp.arange(b.shape[0]) + jnp.searchsorted(ka, kb, side="right")
-    pos_a = jnp.where(ka != SENTINEL, pos_a, capacity)    # pads drop out
-    pos_b = jnp.where(kb != SENTINEL, pos_b, capacity)
-    out = jnp.full((capacity, a.shape[1]), SENTINEL, jnp.int32)
-    out = out.at[pos_a].set(a.astype(jnp.int32), mode="drop")
-    return out.at[pos_b].set(b.astype(jnp.int32), mode="drop")
+    with jax.named_scope(MERGE_SCOPE):
+        ka = compact_key(a, domain)
+        kb = compact_key(b, domain)
+        if ka is None or kb is None:
+            rows = jnp.concatenate([a, b], axis=0)
+            if rows.shape[0] < capacity:
+                pad = jnp.full(
+                    (capacity - rows.shape[0], rows.shape[1]), SENTINEL, jnp.int32
+                )
+                rows = jnp.concatenate([rows, pad], axis=0)
+            order = lexsort_rows(rows)
+            return rows[order][:capacity]
+        pos_a = jnp.arange(a.shape[0]) + jnp.searchsorted(kb, ka, side="left")
+        pos_b = jnp.arange(b.shape[0]) + jnp.searchsorted(ka, kb, side="right")
+        pos_a = jnp.where(ka != SENTINEL, pos_a, capacity)    # pads drop out
+        pos_b = jnp.where(kb != SENTINEL, pos_b, capacity)
+        out = jnp.full((capacity, a.shape[1]), SENTINEL, jnp.int32)
+        out = out.at[pos_a].set(a.astype(jnp.int32), mode="drop")
+        return out.at[pos_b].set(b.astype(jnp.int32), mode="drop")
 
 
 @dataclass

@@ -42,6 +42,8 @@ WORD = 32
 TM = 128          # output row tile
 TW = 128          # word tile (4096 bits) along K and N when the word count allows
 FULL_MAX = 256    # word counts up to this run as one full-width block
+#: The device scope of the kernels (``jax.named_scope``, op metadata only).
+PBME_SCOPE = "pbme"
 
 
 def padded_words(words: int) -> int:
@@ -164,7 +166,8 @@ def bitmm_call(a: jax.Array, b: jax.Array, *, interpret: bool) -> jax.Array:
     a: uint32[M, Kw]; b: uint32[Kw*32, Nw]; M a multiple of ``TM``; Kw and
     Nw multiples of ``TW`` or at most ``FULL_MAX``.
     """
-    return _call(_bitmm_kernel, a, b, (), 1, interpret)
+    with jax.named_scope(PBME_SCOPE):
+        return _call(_bitmm_kernel, a, b, (), 1, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -173,5 +176,5 @@ def bitmm_fused_delta_call(
 ) -> tuple[jax.Array, jax.Array]:
     """One fused PBME iteration: (Δ', M') = ((A⊛B) & ~M, M | Δ')."""
     assert m_cur.shape == (a.shape[0], b.shape[1]), (a.shape, b.shape, m_cur.shape)
-    delta, m_new = _call(_bitmm_fused_kernel, a, b, (m_cur,), 2, interpret)
-    return delta, m_new
+    with jax.named_scope(PBME_SCOPE):
+        return _call(_bitmm_fused_kernel, a, b, (m_cur,), 2, interpret)

@@ -18,10 +18,18 @@ Design constraints (this code sits inside the semi-naïve inner loop):
   (appends are single-threaded by construction, no lock on the hot path)
   and keeps its own open-span stack, so parenting never crosses threads:
   the server's writer thread, checkpointer thread, and reader threads each
-  produce an independent, correctly-nested lane in the export.
+  produce an independent, correctly-nested lane in the export.  A buffer
+  is registered with its thread, not under the thread's ident (which the
+  OS reuses), so the spans of a thread that has finished stay until
+  :meth:`Tracer.clear`.
 * **Bounded** — per-thread buffers keep the newest ``max_spans_per_thread``
   finished spans; a long-lived server cannot accumulate unbounded trace
-  state while tracing stays on.
+  state per thread while tracing stays on.
+* **Profiler clock** — ``enable(annotate=...)`` takes a factory shaped like
+  ``jax.profiler.TraceAnnotation``; every span then also opens one, named
+  like the span and carrying its ``span_id``, so a profiler trace holds the
+  program's spans on the same clock as the device's operations.  This
+  module imports no JAX: the caller passes the factory in.
 """
 
 from __future__ import annotations
@@ -58,12 +66,13 @@ class Span:
 
     __slots__ = (
         "name", "cat", "args", "start_ns", "dur_ns",
-        "tid", "span_id", "parent_id", "_tracer",
+        "tid", "span_id", "parent_id", "_tracer", "_annotation",
     )
 
     def __init__(self):
         self.args: dict[str, Any] = {}
         self.dur_ns = -1          # -1 = still open (or an instant event)
+        self._annotation = None   # the profiler annotation this span opened
 
     def set(self, **attrs) -> "Span":
         """Attach/overwrite attributes; exported as Chrome-trace ``args``."""
@@ -93,32 +102,43 @@ class Tracer:
         self.enabled = False
         self.max_spans_per_thread = max_spans_per_thread
         self._lock = threading.Lock()
-        # tid → (thread name, buffer); buffers are append-only from their
-        # owning thread, snapshot by slice from the exporter
-        self._buffers: dict[int, tuple[str, list[Span]]] = {}
+        # (thread, buffer) per thread that recorded; buffers are append-only
+        # from their owning thread, snapshot by slice from the exporter
+        self._buffers: list[tuple[threading.Thread, list[Span]]] = []
         self._local = _ThreadState()
+        self._annotate: Callable | None = None
         self._next_id = itertools.count(1).__next__
         self._t0_ns = time.perf_counter_ns()
 
     # -- control -------------------------------------------------------------
 
     def enable(
-        self, max_spans_per_thread: int | None = None, clear: bool = True
+        self,
+        max_spans_per_thread: int | None = None,
+        clear: bool = True,
+        annotate: Callable | None = None,
     ) -> None:
+        """Start recording.  ``annotate`` (e.g. ``jax.profiler.
+        TraceAnnotation``) is called as ``annotate(name, span_id=...)`` for
+        every span and entered and exited with it, on the span's thread."""
         if max_spans_per_thread is not None:
             self.max_spans_per_thread = max_spans_per_thread
         if clear:
             self.clear()
+        self._annotate = annotate
         self.enabled = True
 
     def disable(self) -> None:
         self.enabled = False
 
     def clear(self) -> None:
-        """Drop every recorded span (open-span stacks are per-thread and
-        survive; their spans record when they close if tracing is on)."""
+        """Drop every recorded span, and the buffers of finished threads
+        (open-span stacks are per-thread and survive; their spans record
+        when they close if tracing is on)."""
         with self._lock:
-            for _name, buf in self._buffers.values():
+            self._buffers = [(th, buf) for th, buf in self._buffers
+                             if th.is_alive()]
+            for _th, buf in self._buffers:
                 del buf[:]
         self._t0_ns = time.perf_counter_ns()
 
@@ -143,8 +163,16 @@ class Tracer:
         stack = self._local.stack
         sp.parent_id = stack[-1].span_id if stack else 0
         stack.append(sp)
+        if self._annotate is not None:
+            sp._annotation = self._annotate(name, span_id=sp.span_id)
+            sp._annotation.__enter__()
         sp.start_ns = time.perf_counter_ns()
         return sp
+
+    def current(self) -> Span | None:
+        """The calling thread's innermost open span, or None."""
+        stack = self._local.stack
+        return stack[-1] if stack else None
 
     def instant(self, name: str, cat: str = "", **attrs) -> None:
         """Record a zero-duration marker event (Chrome-trace ``ph: "i"``)."""
@@ -166,6 +194,8 @@ class Tracer:
 
     def _finish(self, sp: Span) -> None:
         sp.dur_ns = time.perf_counter_ns() - sp.start_ns
+        if sp._annotation is not None:
+            sp._annotation.__exit__(None, None, None)
         stack = self._local.stack
         # ``with`` guarantees LIFO exit; tolerate a foreign stack anyway
         # (e.g. a span entered before enable() toggled mid-flight)
@@ -180,9 +210,7 @@ class Tracer:
         if st.buf is None:
             st.buf = []
             with self._lock:
-                self._buffers[threading.get_ident()] = (
-                    threading.current_thread().name, st.buf,
-                )
+                self._buffers.append((threading.current_thread(), st.buf))
         st.buf.append(sp)
         if len(st.buf) > 2 * self.max_spans_per_thread:
             del st.buf[: -self.max_spans_per_thread]
@@ -209,9 +237,9 @@ class Tracer:
     def spans(self) -> list[Span]:
         """Snapshot of recorded spans across all threads, by start time."""
         with self._lock:
-            bufs = [(name, buf) for name, buf in self._buffers.values()]
+            bufs = list(self._buffers)
         out: list[Span] = []
-        for _name, buf in bufs:
+        for _th, buf in bufs:
             out.extend(buf[-self.max_spans_per_thread:])
         out.sort(key=lambda s: s.start_ns)
         return out
@@ -230,7 +258,7 @@ class Tracer:
         t0 = self._t0_ns
         events: list[dict] = []
         with self._lock:
-            names = {tid: name for tid, (name, _buf) in self._buffers.items()}
+            names = {th.ident: th.name for th, _buf in self._buffers}
         for tid, name in names.items():
             events.append(
                 {

@@ -568,18 +568,20 @@ class MaterializedInstance:
         rows = self._tuple_rows(handles, rel)
         if rows is None:
             return np.zeros((0, self.plan.program.arity_of(rel)), np.int32)
-        if set(bounds) == {0}:
-            # tables are sorted by column 0 (pads last): binary search + slice
-            lo, hi = (
-                bounds[0] if isinstance(bounds[0], tuple) else (bounds[0], bounds[0])
-            )
-            col = rows[:, 0]
-            l = int(jnp.searchsorted(col, lo, side="left"))
-            h = int(jnp.searchsorted(col, hi, side="right"))
-            with _TRACE.span("device.sync", "serve", what="query_rows"):
-                return np.asarray(rows[l:h])
-        out, count = self.cache.select(rows, bounds)
+        # one span over every host read of the lookup: the searches' or the
+        # selection's count, then the rows
         with _TRACE.span("device.sync", "serve", what="query_rows"):
+            if set(bounds) == {0}:
+                # tables are sorted by column 0 (pads last): binary search + slice
+                lo, hi = (
+                    bounds[0] if isinstance(bounds[0], tuple)
+                    else (bounds[0], bounds[0])
+                )
+                col = rows[:, 0]
+                l = int(jnp.searchsorted(col, lo, side="left"))
+                h = int(jnp.searchsorted(col, hi, side="right"))
+                return np.asarray(rows[l:h])
+            out, count = self.cache.select(rows, bounds)
             return np.asarray(out[:count])
 
     def _tuple_rows(self, handles, rel: str):
@@ -863,10 +865,15 @@ class MaterializedInstance:
         if stats.requested == 0:
             stats.epoch = self.epoch
             return self._finish_update(stats, t0)
+        # under the server's writer.apply, the transaction's spans share
+        # the group's request ids
+        outer = _TRACE.current() if _TRACE.enabled else None
+        rids = outer.args.get("rids") if outer is not None else None
         with _TRACE.span(
             "txn.apply", "serve",
             kind=stats.kind, relation=stats.relation,
             requested=stats.requested, ops=len(norm),
+            **({"rids": rids} if rids is not None else {}),
         ) as sp:
             result = self._transactional(
                 stats,

@@ -385,9 +385,14 @@ class DatalogServer:
         self._m_update_seconds = reg.histogram(
             "datalog_update_seconds", "Per-update-request service time (seconds)"
         )
-        self._m_queue_wait = reg.histogram(
-            "datalog_queue_wait_seconds", "Time from submit to admission"
-        )
+        self._m_queue_wait = {
+            kind: reg.histogram(
+                "datalog_queue_wait_seconds",
+                "Time from submit to admission, by kind",
+                labels={"kind": kind},
+            )
+            for kind in ("query", "txn", "insert", "delete")
+        }
         # -- admission control (ServerLimits) ---------------------------------
         self._m_shed = {
             kind: reg.counter(
@@ -943,12 +948,10 @@ class DatalogServer:
             # legacy mode: apply inline — a thread would be join()ed
             # immediately anyway
             t0 = self._clock()
-            prids = tuple(r.rid for r in group if r.profile)
             with _TRACE.span(
                 "writer.apply", "serve",
                 kind=group[0].kind, batch=len(group),
-                base_epoch=self.instance.epoch,
-                **({"profile_rids": prids} if prids else {}),
+                base_epoch=self.instance.epoch, **self._group_ids(group),
             ) as sp:
                 results = self._apply_update_group(group)
                 sp.set(epoch=self.instance.epoch)
@@ -1119,16 +1122,19 @@ class DatalogServer:
                         # estimate, nothing allocated per request
                         results[r.rid] = self._apply(fn, r.rid)
                         continue
-                    results[r.rid] = self._serve_one_query(r, fn, snap)
+                    results[r.rid] = self._serve_one_query(
+                        r, fn, snap, queue_wait_s=t0 - r.submitted
+                    )
         finally:
             snap.release()
         self._record(group, results, t0, self._clock(), snap.epoch, concurrent)
 
-    def _serve_one_query(self, r: _Request, fn, snap):
+    def _serve_one_query(self, r: _Request, fn, snap, queue_wait_s: float):
         """One traced/profiled query: a per-request ``query`` span carrying
-        the result cardinality — and, when profiled, the selection estimate
-        plus a ``query``-level misestimation observation."""
-        attrs = {"rid": r.rid, "rel": r.rel}
+        its queue wait (submit to admission, seconds) and the result
+        cardinality — and, when profiled, the selection estimate plus a
+        ``query``-level misestimation observation."""
+        attrs = {"rid": r.rid, "rel": r.rel, "queue_wait_s": queue_wait_s}
         if r.profile:
             attrs["profile_rid"] = r.rid
         with _TRACE.span("query", "serve", **attrs) as qs:
@@ -1261,18 +1267,15 @@ class DatalogServer:
         t0 = self._clock()
         out: dict = {}
         base_epoch = self.instance.epoch
-
-        prids = tuple(r.rid for r in group if r.profile)
+        ids = self._group_ids(group)
 
         def work() -> None:
             # epoch lineage: base_epoch is what this group builds on;
-            # the published epoch lands on the span when the apply returns.
-            # profile_rids marks this span as the subtree root for every
-            # profiled member of the group (see repro.obs.profile)
+            # the published epoch lands on the span when the apply returns
             with _TRACE.span(
                 "writer.apply", "serve",
                 kind=group[0].kind, batch=len(group), base_epoch=base_epoch,
-                **({"profile_rids": prids} if prids else {}),
+                **ids,
             ) as sp:
                 try:
                     out["results"] = self._apply_update_group(group)
@@ -1284,6 +1287,18 @@ class DatalogServer:
         th = threading.Thread(target=work, name="datalog-writer", daemon=True)
         self._writer = (th, group, out, t0, base_epoch)
         th.start()
+
+    @staticmethod
+    def _group_ids(group: list[_Request]) -> dict:
+        """``writer.apply``'s request ids: every member's (``rids``) when
+        tracing, so the group's spans share them, and the profiled members'
+        (``profile_rids``), which mark the span as the root of their
+        profile subtrees (see repro.obs.profile)."""
+        ids = {"rids": tuple(r.rid for r in group)} if _TRACE.enabled else {}
+        prids = tuple(r.rid for r in group if r.profile)
+        if prids:
+            ids["profile_rids"] = prids
+        return ids
 
     def _reap_writer(self) -> None:
         """Join the in-flight update batch (if any) and record its results."""
@@ -1607,7 +1622,12 @@ class DatalogServer:
             counter.inc()
             if isinstance(results[r.rid], RequestError):
                 self._m_errors.inc()
-            self._m_queue_wait.observe(t0 - r.submitted)
+            wait = self._m_queue_wait.get(r.kind)
+            if wait is None:
+                wait = self._m_queue_wait[r.kind] = self.metrics_registry.histogram(
+                    "datalog_queue_wait_seconds", labels={"kind": r.kind}
+                )
+            wait.observe(t0 - r.submitted)
             service_hist.observe(per_req)
             if r.profile:
                 self._finish_profile(r, results[r.rid], t0, per_req, epoch)

@@ -163,6 +163,61 @@ def test_buffer_bound():
     assert spans[-1].args["i"] == 99              # newest survive
 
 
+def test_finished_threads_keep_their_spans():
+    """Short-lived threads, each started after the last one ended, so the
+    OS can hand every one the same ident: all of their spans survive."""
+    tr = Tracer()
+    tr.enable()
+    for i in range(50):
+        th = threading.Thread(target=lambda i=i: tr.span("w", "t", i=i).__exit__())
+        th.start()
+        th.join()
+    assert sorted(s.args["i"] for s in tr.spans()) == list(range(50))
+    tr.clear()
+    assert tr.spans() == []
+    assert len(tr._buffers) <= 1              # finished threads' buffers go
+
+
+def test_annotate_hook_wraps_every_span_on_its_thread():
+    calls = []
+
+    class Annotation:
+        def __init__(self, name, **stats):
+            self.key = (name, stats["span_id"])
+
+        def __enter__(self):
+            calls.append(("enter", *self.key, threading.get_ident()))
+            return self
+
+        def __exit__(self, *exc):
+            calls.append(("exit", *self.key, threading.get_ident()))
+
+    tr = Tracer()
+    tr.enable(annotate=Annotation)
+    with tr.span("outer", "t"):
+        with tr.span("inner", "t"):
+            pass
+    th = threading.Thread(target=lambda: tr.span("other", "t").__exit__())
+    th.start()
+    th.join()
+    tr.instant("mark", "t")                   # instants open no annotation
+    spans = {s.name: s for s in tr.spans() if s.dur_ns >= 0}
+    me = threading.get_ident()
+    o, i, x = spans["outer"].span_id, spans["inner"].span_id, spans["other"].span_id
+    assert calls == [
+        ("enter", "outer", o, me), ("enter", "inner", i, me),
+        ("exit", "inner", i, me), ("exit", "outer", o, me),
+        ("enter", "other", x, th.ident), ("exit", "other", x, th.ident),
+    ]
+    tr.disable()
+    with tr.span("off", "t"):
+        pass
+    tr.enable(clear=False)                    # enabled without the hook
+    with tr.span("plain", "t"):
+        pass
+    assert len(calls) == 6
+
+
 def test_chrome_export_roundtrip(tmp_path):
     tr = Tracer()
     tr.enable()
@@ -377,6 +432,60 @@ def test_server_txn_span_tree_and_metrics(tmp_path):
     assert 0.0 <= m["datalog_plan_cache_hit_rate"] <= 1.0
     json.dumps(m)                                 # snapshot stays JSON-clean
     assert "datalog_requests_total" in srv.metrics_prometheus()
+
+
+def test_one_requests_spans_share_its_id():
+    from repro.core.engine import EngineConfig
+    from repro.obs.trace import TRACER
+    from repro.serve_datalog import DatalogServer, MaterializedInstance
+
+    arc = _chain(12)
+    base = np.concatenate([arc[:5], arc[6:]])
+    inst = MaterializedInstance(
+        "tc(x,y) :- arc(x,y).\ntc(x,y) :- tc(x,z), arc(z,y).",
+        {"arc": base}, EngineConfig(backend="tuple"),
+    )
+    srv = DatalogServer(inst)
+    TRACER.enable()
+    try:
+        t1 = srv.submit_txn([("insert", "arc", arc[5:6])])
+        t2 = srv.submit_txn([("insert", "arc", arc[5:6])])
+        q = srv.submit_query("tc", src=0)
+        srv.run()
+        spans = TRACER.spans()
+    finally:
+        TRACER.disable()
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    assert [s.args["rids"] for s in by["writer.apply"]] == [(t1, t2)]
+    assert [s.args["rids"] for s in by["txn.apply"]] == [(t1, t2)]
+    (query,) = by["query"]
+    assert query.args["rid"] == q and query.args["queue_wait_s"] >= 0
+    # one device.sync per lookup, under its query span
+    (sync,) = [s for s in by["device.sync"] if s.parent_id == query.span_id]
+    assert sync.args["what"] == "query_rows"
+
+
+def test_queue_wait_is_labelled_by_kind():
+    from repro.core.engine import EngineConfig
+    from repro.serve_datalog import DatalogServer, MaterializedInstance
+
+    arc = _chain(12)
+    inst = MaterializedInstance(
+        "tc(x,y) :- arc(x,y).\ntc(x,y) :- tc(x,z), arc(z,y).",
+        {"arc": arc[:-1]}, EngineConfig(backend="tuple"),
+    )
+    srv = DatalogServer(inst)
+    for s in range(3):
+        srv.submit_query("tc", src=s)
+    srv.submit_txn([("insert", "arc", arc[-1:])])
+    srv.run()
+    m = srv.metrics()
+    assert m['datalog_queue_wait_seconds{kind="query"}']["count"] == 3
+    assert m['datalog_queue_wait_seconds{kind="txn"}']["count"] == 1
+    assert m['datalog_queue_wait_seconds{kind="insert"}']["count"] == 0
+    assert "datalog_queue_wait_seconds" not in m
 
 
 def test_server_stats_snapshot_under_concurrent_mutation():

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import json
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -183,6 +184,32 @@ def test_profiled_query_estimate_vs_actual():
     )
     prom = srv.metrics_prometheus()
     assert 'datalog_misestimation_ratio_count{level="query"} 1' in prom
+
+
+@pytest.mark.parametrize("bound", [{"src": 0}, {"dst": 5}])
+def test_profiled_lookup_syncs_under_one_device_sync_span(bound, monkeypatch):
+    """A bound-first-column lookup (two binary searches, then the slice) and
+    a selection (its count, then the rows) each read the device under one
+    ``device.sync`` span, and the profile's ``device_sync_seconds`` is it."""
+    inst = _csda_instance()
+    srv = DatalogServer(inst)
+    searched_in = []
+    search = jnp.searchsorted
+
+    def spy(*a, **k):
+        searched_in.append(getattr(TRACER.current(), "name", None))
+        return search(*a, **k)
+
+    monkeypatch.setattr(jnp, "searchsorted", spy)
+    qid = srv.submit_query("null", profile=True, **bound)
+    srv.run()
+    if "src" in bound:
+        assert searched_in == ["device.sync"] * 2
+    prof = srv.profile(qid)
+    syncs = [n for r in prof.roots for n in r.walk() if n.name == "device.sync"]
+    assert [n.attrs["what"] for n in syncs] == ["query_rows"]
+    assert prof.device_sync_seconds == syncs[0].seconds > 0
+    assert prof.rows == len(srv.done[qid])
 
 
 def test_concurrent_profiles_do_not_leak_across_requests():
