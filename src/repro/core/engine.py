@@ -29,7 +29,7 @@ import numpy as np
 
 from repro.core.aggregates import eval_expr, groupby_aggregate
 from repro.core.analyzer import Stratification, Stratum, analyze
-from repro.core.ast import Agg, Atom, Program, Rule, Var
+from repro.core.ast import Agg, Atom, Const, Program, Rule, Var
 from repro.core.joins import (
     Bindings,
     antijoin,
@@ -138,6 +138,43 @@ class TupleView:
 
 def _empty_view(arity: int, domain: int) -> TupleView:
     return TupleView(jnp.full((1, arity), SENTINEL, jnp.int32), 0, domain)
+
+
+def wide_preds(program: Program, domain: int) -> set[str]:
+    """IDB predicates whose tuples may hold values outside ``[0, domain)``.
+
+    An aggregate that computes a value (SUM, COUNT, AVG, or MIN/MAX over a
+    sum) or a head constant outside the domain makes such a value, and every
+    predicate that reads one inherits it.  Compact keys over ``domain``
+    would alias those rows, so their tables keep no key (``domain`` 0).
+    """
+
+    def in_domain(term) -> bool:
+        if isinstance(term, Var):
+            return True
+        if isinstance(term, Const):
+            return 0 <= term.value < domain
+        arg = term.arg
+        if term.op not in ("MIN", "MAX"):
+            return False
+        if arg.vars:
+            return len(arg.vars) == 1 and arg.const == 0
+        return 0 <= arg.const < domain
+
+    wide = {
+        r.head_pred for r in program.rules
+        if not all(map(in_domain, r.head_terms))
+    }
+    grew = True
+    while grew:
+        grew = False
+        for r in program.rules:
+            if r.head_pred not in wide and any(
+                a.pred in wide for a in r.positive_atoms
+            ):
+                wide.add(r.head_pred)
+                grew = True
+    return wide
 
 
 # --------------------------------------------------------------------------
@@ -423,6 +460,7 @@ class Engine:
         """Choose the physical representation per IDB (dense specializations)."""
         cfg = self.config
         kinds: dict[str, str] = {}
+        wide = wide_preds(strat.program, self.domain)
         for pred in stratum.preds:
             arity = strat.pred_arity(pred)
             rules = stratum.rules_for(pred)
@@ -460,8 +498,9 @@ class Engine:
             else:
                 kinds[pred] = "tuple"
                 if fresh or pred not in store:
+                    key_domain = 0 if pred in wide else self.domain
                     store[pred] = TupleRelation.empty(
-                        pred, arity, self.domain, cfg.capacity_min
+                        pred, arity, key_domain, cfg.capacity_min
                     )
         self._kinds = kinds
         return kinds
@@ -553,15 +592,15 @@ class Engine:
             parts = []
             for rows, valid, _rule in buffers:
                 cap_i = next_bucket(rows.shape[0], cfg.capacity_min)
-                srt = _sort_pad(rows, cap_i, self.domain)
-                dd, _ = _dedup_sorted(srt, self.domain)
+                srt = _sort_pad(rows, cap_i, handle.domain)
+                dd, _ = _dedup_sorted(srt, handle.domain)
                 parts.append(dd)
             cand = jnp.concatenate(parts, axis=0)
         rec.candidates = int(jnp.sum(cand[:, 0] != SENTINEL))
 
         cap = next_bucket(cand.shape[0], cfg.capacity_min)
-        cand = _sort_pad(cand, cap, self.domain)
-        deduped, dd_count = _dedup_sorted(cand, self.domain)
+        cand = _sort_pad(cand, cap, handle.domain)
+        deduped, dd_count = _dedup_sorted(cand, handle.domain)
         rec.deduped = int(dd_count)
 
         delta_rows, delta_count, strategy = set_difference(
@@ -569,7 +608,7 @@ class Engine:
             rec.deduped,
             handle.rows,
             handle.count,
-            self.domain,
+            handle.domain,
             dsd_state[pred],
             mode=cfg.dsd if cfg.enable_oof or cfg.dsd != "dynamic" else "opsd",
         )
@@ -579,7 +618,7 @@ class Engine:
         store[pred] = handle.merge(delta_rows, delta_count)
         rec.full = store[pred].count
         dcap = next_bucket(max(delta_count, 1), cfg.capacity_min)
-        deltas[pred] = TupleView(delta_rows[:dcap], delta_count, self.domain)
+        deltas[pred] = TupleView(delta_rows[:dcap], delta_count, handle.domain)
         return rec
 
     # -- DRed retraction: the over-delete / re-derive driver -------------------
@@ -674,20 +713,19 @@ class Engine:
                     if not bufs:
                         continue
                     cand = jnp.concatenate([b[0] for b in bufs], axis=0)
+                    dom = store[pred].domain
                     cand = _sort_pad(
-                        cand, next_bucket(cand.shape[0], cfg.capacity_min), self.domain
+                        cand, next_bucket(cand.shape[0], cfg.capacity_min), dom
                     )
-                    cand, _ = _dedup_sorted(cand, self.domain)
+                    cand, _ = _dedup_sorted(cand, dom)
                     new_h, removed, r_count = store[pred].delete_rows(cand)
                     if r_count == 0:
                         continue
                     store[pred] = new_h
                     dcap = next_bucket(r_count, cfg.capacity_min)
-                    next_frontier[pred] = TupleView(
-                        removed[:dcap], r_count, self.domain
-                    )
+                    next_frontier[pred] = TupleView(removed[:dcap], r_count, dom)
                     acc = nabla.get(pred) or TupleRelation.empty(
-                        pred, strat.pred_arity(pred), self.domain, cfg.capacity_min
+                        pred, strat.pred_arity(pred), dom, cfg.capacity_min
                     )
                     nabla[pred] = acc.merge(removed, r_count)
                 frontier = next_frontier
@@ -697,7 +735,7 @@ class Engine:
         deltas.update(changed)
         dsd_state = {p: DSDState(alpha=cfg.alpha) for p in stratum.preds}
         for pred, acc in nabla.items():
-            deltas[NABLA + pred] = TupleView(acc.rows, acc.count, self.domain)
+            deltas[NABLA + pred] = TupleView(acc.rows, acc.count, acc.domain)
         seed_groups = rederive_seed_variants(stratum, set(changed), nabla)
         for pred in stratum.preds:
             if not seed_groups[pred]:
@@ -734,13 +772,13 @@ class Engine:
                 # steady-state delete latency stays delta-sized.
                 rows, count, _ = set_difference(
                     acc.rows, acc.count, new_h.rows, new_h.count,
-                    self.domain, DSDState(),
+                    new_h.domain, DSDState(),
                 )
                 if count:
                     net_deleted[pred] = TupleView(
                         rows[: next_bucket(count, cfg.capacity_min)],
                         count,
-                        self.domain,
+                        new_h.domain,
                     )
                 continue
             # Mixed upstream diff (deletions + insertions): the stratum can
@@ -753,13 +791,13 @@ class Engine:
                     continue
                 rows, count, _ = set_difference(
                     src.rows, src.count, dst.rows, dst.count,
-                    self.domain, DSDState(),
+                    new_h.domain, DSDState(),
                 )
                 if count:
                     out[pred] = TupleView(
                         rows[: next_bucket(count, cfg.capacity_min)],
                         count,
-                        self.domain,
+                        new_h.domain,
                     )
         iters = rounds + (
             self.stats.iterations.get(stratum.index, 0) if stratum.recursive else 0
@@ -794,7 +832,7 @@ class Engine:
         if isinstance(handle, TupleRelation):
             if use_delta:
                 return _empty_view(atom.arity, self.domain)
-            return TupleView(handle.rows, handle.count, self.domain)
+            return TupleView(handle.rows, handle.count, handle.domain)
         # dense handles: materialize a tuple view
         cap = next_bucket(
             max(handle.delta_count if use_delta else handle.count, 1),
@@ -875,7 +913,7 @@ class Engine:
         for atom in atoms:
             if atom.negated:
                 view = self._view_for(strat, stratum, store, deltas, atom, False)
-                bindings = antijoin(bindings, atom, view.rows, self.domain)
+                bindings = antijoin(bindings, atom, view.rows, view.domain)
 
         assert not pending_cmps, f"unapplied comparisons in {rule}"
 

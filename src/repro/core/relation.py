@@ -24,7 +24,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.relational.sort import SENTINEL, compact_key, lexsort_rows, unique_mask
+from repro.obs.trace import TRACER as _TRACE
+from repro.relational.sort import (
+    SENTINEL,
+    compact_key,
+    compact_key_fits,
+    decode_key,
+    lexsort_rows,
+    unique_mask,
+)
 
 INT_INF = int(SENTINEL)
 
@@ -44,6 +52,21 @@ def empty_delta(arity: int, minimum: int = 128) -> jax.Array:
     ``count == 0``.
     """
     return jnp.full((next_bucket(0, minimum), arity), SENTINEL, jnp.int32)
+
+
+def pad_delta(rows: jax.Array, capacity: int) -> jax.Array:
+    """A merge's delta, SENTINEL-padded to at least ``capacity >> 6`` rows.
+
+    Every delta under 1/64 of a table then merges into it through one
+    program, for at most 1/64 more keys to sort: the TPU's compiler takes
+    seconds to build a program around a large sort (18 s at 2^25 rows on
+    a v5e), and a new delta bucket would otherwise build one mid-stream.
+    """
+    short = (capacity >> 6) - rows.shape[0]
+    if short <= 0:
+        return rows
+    pad = jnp.full((short, rows.shape[1]), SENTINEL, jnp.int32)
+    return jnp.concatenate([rows, pad])
 
 
 #: The device scope of the tuple table's sort, dedup and merge programs
@@ -112,7 +135,8 @@ class TupleRelation:
     arity: int
     rows: jax.Array          # int32[capacity, arity], lex-sorted, pads last
     count: int               # host-side valid-row count (the OOF statistic)
-    domain: int              # active-domain size (compact-key eligibility)
+    domain: int              # active-domain size (compact-key eligibility);
+                             # 0: values unbounded, rows sort without a key
     _by_col: dict[int, tuple[jax.Array, jax.Array]] = field(default_factory=dict)
 
     @property
@@ -150,7 +174,15 @@ class TupleRelation:
         cap = self.capacity
         while cap < new_count:
             cap *= 2
-        merged = _merge_sorted(self.rows, delta_rows, cap, self.domain)
+        if _TRACE.enabled:
+            fits = compact_key_fits(self.arity, self.domain)
+            _TRACE.instant(
+                MERGE_SCOPE, "tuple", path="key_sort" if fits else "lexsort",
+                table_rows=self.count, delta_rows=delta_count, capacity=cap,
+            )
+        merged = _merge_sorted(
+            self.rows, pad_delta(delta_rows, self.capacity), cap, self.domain
+        )
         return TupleRelation(self.name, self.arity, merged, new_count, self.domain)
 
     def insert(self, data: np.ndarray) -> tuple["TupleRelation", jax.Array, int]:
@@ -188,8 +220,8 @@ class TupleRelation:
         # constants outside [0, domain) cannot be present (the table invariant
         # behind compact keys) — drop them, or the base-``domain`` key packing
         # would alias e.g. (a, domain) onto (a+1, 0) and delete a tuple the
-        # caller never named
-        if data.size:
+        # caller never named (a table with ``domain`` 0 keeps no key)
+        if data.size and self.domain > 0:
             data = data[((data >= 0) & (data < self.domain)).all(axis=1)]
         if data.size == 0 or self.count == 0:
             return self, empty_delta(self.arity), 0
@@ -247,13 +279,19 @@ class TupleRelation:
 
 @functools.partial(jax.jit, static_argnames=("capacity", "domain"))
 def _merge_sorted(a: jax.Array, b: jax.Array, capacity: int, domain: int) -> jax.Array:
-    """Merge two sorted disjoint tables into one sorted ``capacity`` table.
+    """Merge two sorted tables into one sorted ``capacity`` table.
 
-    Compact-key path is a true O(n) rank merge: each valid row's output
-    position is its own index plus the count of smaller rows on the other
-    side (two ``searchsorted`` passes + two scatters) — no full-table sort.
-    This is the serving hot path: one merge per IDB per iteration, over
-    tables that dwarf the delta.
+    Compact-key path: sort the two tables' keys together (one single-operand
+    sort, pads last), pad or cut to ``capacity`` and unpack the rows from
+    the keys arithmetically.  The key is a bijection on rows over
+    ``[0, domain)`` (a table whose values may leave it has ``domain`` 0 and
+    sorts whole rows), so this is the exact merge, duplicates included.
+    It touches no row by index: on the TPU a per-element gather or scatter
+    over the big table (a ``searchsorted`` of every table row into the
+    delta, then a row scatter) costs seconds where the sort streams.  This
+    is the serving hot path: one merge per IDB per iteration, over tables
+    that dwarf the delta.  Callers guarantee that the valid rows fit in
+    ``capacity``.
     """
     with jax.named_scope(MERGE_SCOPE):
         ka = compact_key(a, domain)
@@ -267,13 +305,11 @@ def _merge_sorted(a: jax.Array, b: jax.Array, capacity: int, domain: int) -> jax
                 rows = jnp.concatenate([rows, pad], axis=0)
             order = lexsort_rows(rows)
             return rows[order][:capacity]
-        pos_a = jnp.arange(a.shape[0]) + jnp.searchsorted(kb, ka, side="left")
-        pos_b = jnp.arange(b.shape[0]) + jnp.searchsorted(ka, kb, side="right")
-        pos_a = jnp.where(ka != SENTINEL, pos_a, capacity)    # pads drop out
-        pos_b = jnp.where(kb != SENTINEL, pos_b, capacity)
-        out = jnp.full((capacity, a.shape[1]), SENTINEL, jnp.int32)
-        out = out.at[pos_a].set(a.astype(jnp.int32), mode="drop")
-        return out.at[pos_b].set(b.astype(jnp.int32), mode="drop")
+        key = jnp.sort(jnp.concatenate([ka, kb]).astype(jnp.int32))
+        if key.shape[0] < capacity:
+            pad = jnp.full((capacity - key.shape[0],), SENTINEL, jnp.int32)
+            key = jnp.concatenate([key, pad])
+        return decode_key(key[:capacity], a.shape[1], domain)
 
 
 @dataclass

@@ -23,6 +23,11 @@ import jax.numpy as jnp
 SENTINEL = jnp.iinfo(jnp.int32).max
 
 
+def compact_key_fits(arity: int, domain: int) -> bool:
+    """Whether ``arity``-column rows over ``domain`` pack into one key."""
+    return arity == 1 or (0 < domain and domain ** arity < SENTINEL)
+
+
 def compact_key(rows: jax.Array, domain: int) -> jax.Array | None:
     """Pack an ``int32[n, k]`` tuple table into a single ``int32[n]`` key.
 
@@ -32,16 +37,34 @@ def compact_key(rows: jax.Array, domain: int) -> jax.Array | None:
     Padding rows map to SENTINEL (all-SENTINEL rows stay maximal).
     """
     arity = rows.shape[1]
+    if not compact_key_fits(arity, domain):
+        return None
     if arity == 1:
         return rows[:, 0]
-    if domain <= 0 or domain ** arity >= SENTINEL:
-        return None
     key = rows[:, 0]
     for c in range(1, arity):
         key = key * domain + rows[:, c]
     # Remap pads: any row containing SENTINEL is padding.
     is_pad = jnp.any(rows == SENTINEL, axis=1)
     return jnp.where(is_pad, SENTINEL, key)
+
+
+def decode_key(key: jax.Array, arity: int, domain: int) -> jax.Array:
+    """Inverse of :func:`compact_key`: ``int32[n]`` keys → ``int32[n, arity]``.
+
+    Valid keys unpack by repeated ``divmod`` by ``domain``; a SENTINEL key
+    becomes an all-SENTINEL pad row.
+    """
+    if arity == 1:
+        return key[:, None]
+    cols = []
+    rest = key
+    for _ in range(arity - 1):
+        cols.append(rest % domain)
+        rest = rest // domain
+    cols.append(rest)
+    rows = jnp.stack(cols[::-1], axis=1)
+    return jnp.where((key == SENTINEL)[:, None], SENTINEL, rows)
 
 
 def lexsort_rows(rows: jax.Array) -> jax.Array:

@@ -1460,12 +1460,12 @@ class MaterializedInstance:
             if h.count == old_count:
                 return None
             rows, count, _ = set_difference(
-                h.rows, h.count, old_rows, old_count, txn.domain, DSDState()
+                h.rows, h.count, old_rows, old_count, h.domain, DSDState()
             )
             if count == 0:
                 return None
             return TupleView(
-                rows[: next_bucket(max(count, 1), cap_min)], count, txn.domain
+                rows[: next_bucket(max(count, 1), cap_min)], count, h.domain
             )
         if snap[0] == "dense_set":
             mask = h.member & ~snap[1]

@@ -32,7 +32,7 @@ import numpy as np
 from repro.analysis import AnalysisConfig, AnalysisReport, analyze_program
 from repro.core.analyzer import Stratification, analyze
 from repro.core.ast import Program
-from repro.core.relation import _dedup_sorted, _merge_sorted, _sort_pad
+from repro.core.relation import _dedup_sorted, _merge_sorted, _sort_pad, pad_delta
 from repro.core.seminaive import RuleVariant, delta_variants
 from repro.obs.trace import TRACER as _TRACE
 from repro.relational.sort import SENTINEL
@@ -313,7 +313,7 @@ class PlanCache:
                 _dedup_sorted(srt, domain)
                 # the steady-state serving merge is (table_cap, small Δ) →
                 # table_cap; (b, b) → 2b is the growth merge
-                _merge_sorted(srt, sm, bucket, domain)
+                _merge_sorted(srt, pad_delta(sm, bucket), bucket, domain)
                 _merge_sorted(srt, srt, 2 * bucket, domain)
                 lov = jnp.zeros((arity,), jnp.int32)
                 for col in range(arity):   # every single-column bound pattern
